@@ -507,6 +507,7 @@ def measure_dist_point(n_hosts: int, n_containers: int, horizon: int,
         return {
             "procs": num_procs,
             "overlap": overlap,
+            "backend": ",".join(sorted({m["backend"] for m in metas})),
             "wall_s": res.wall_s,
             "max_worker_wall_s": round(max(m["wall_s"] for m in metas), 2),
             "compile_cache_misses": res.compile_cache_misses,
@@ -527,6 +528,8 @@ def measure_dist_point(n_hosts: int, n_containers: int, horizon: int,
                      / max(arms[den]["max_worker_wall_s"], 1e-9), 2)
 
     return {
+        # the platform the workers ran on, from their own metadata
+        "backend": ",".join(sorted({a["backend"] for a in arms.values()})),
         "n_hosts": n_hosts,
         "n_containers": n_containers,
         "horizon": horizon,
@@ -547,6 +550,11 @@ def measure_dist_point(n_hosts: int, n_containers: int, horizon: int,
 
 def bench_engine(quick: bool = False):
     """Rows + claims for benchmarks.run; writes BENCH_engine.json."""
+    # first, while this process has not touched JAX: the longhorizon entry
+    # measures child processes, and a child can only claim an accelerator
+    # this process does not hold
+    from benchmarks.longhorizon_bench import measure_longhorizon
+    longhorizon = measure_longhorizon(quick=quick)
     import jax
 
     points = []
@@ -622,19 +630,23 @@ def bench_engine(quick: bool = False):
     tune_grad = measure_tune_grad_point(**TUNE_GRAD_SMOKE)
     # the multi-process fabric arms (ISSUE 8): measured in BOTH modes on
     # the same smoke grid so the CI quick gate has a like-for-like
-    # committed twin (bit-identity + compile bill + overlap ratio)
-    sweep_dist = measure_dist_point(**DIST_SMOKE)
+    # committed twin (bit-identity + compile bill + overlap ratio).  The
+    # fabric refuses an accelerator host (its workers could not claim the
+    # chip this process holds), so there the entry says so instead.
+    backend = jax.default_backend()
+    if backend == "cpu":
+        sweep_dist = measure_dist_point(**DIST_SMOKE)
+    else:
+        sweep_dist = {"backend": backend,
+                      "not_measured": f"the dist fabric refuses a "
+                                      f"{backend} host"}
     # the telescoping arm (ISSUE 10): measured in BOTH modes on the same
     # sparse-event long-horizon grid — the gated numbers (bitwise
     # equality, the within-run on/off speedup) are machine-independent
     telescope = measure_telescope_point(**TELESCOPE_SMOKE)
-    from benchmarks.longhorizon_bench import measure_longhorizon
-    longhorizon = measure_longhorizon(quick=quick)
-    backend = jax.default_backend()
     sweep["backend"] = backend
     tune["backend"] = backend
     tune_grad["backend"] = backend
-    sweep_dist["backend"] = backend
     telescope["backend"] = backend
     out = {
         "bench": "engine_tick_throughput",
@@ -692,6 +704,8 @@ def bench_engine(quick: bool = False):
          f"oracle evals = {tune_grad['grad_vs_random']}x, "
          f"vs incumbent {tune_grad['grad_vs_incumbent']}x on "
          f"{tune_grad['objective']}"),
+        ("dist fabric", sweep_dist["not_measured"])
+        if "not_measured" in sweep_dist else
         (f"dist fabric {sweep_dist['cells']} cells (chunk "
          f"{sweep_dist['chunk']}, slab {sweep_dist['slab']}) x "
          f"{{1,2}} procs",
@@ -739,6 +753,8 @@ def main():
     ap.add_argument("--quick", action="store_true",
                     help="only the 100-host tracking points")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows, claims = bench_engine(quick=args.quick)
     for r in rows:
         print(r)

@@ -216,10 +216,23 @@ def run_stacked_child(horizon: int, ceiling_mb: float) -> dict:
     return row
 
 
+def _check_parent_off_device() -> None:
+    """The children run on the default platform, and a chip belongs to one
+    process: if this process already holds an accelerator, a child that
+    needs it would fail or hang.  Refuse instead."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        import jax
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "measure_longhorizon spawns child processes that need the "
+                f"{jax.default_backend()} device this process already "
+                "holds; call it before anything in this process touches JAX")
+
+
 def measure_longhorizon(quick: bool = False) -> dict:
     """The BENCH_engine.json ``longhorizon`` entry."""
-    import jax
-
+    _check_parent_off_device()
     horizon = QUICK_HORIZON if quick else FULL_HORIZON
     stream = run_stream_child(horizon)
     entry = {
@@ -228,7 +241,7 @@ def measure_longhorizon(quick: bool = False) -> dict:
         "horizon": horizon,
         "stacked_buffer_mb": round(
             LONGHORIZON["seeds"] * horizon * 64 / 2**20, 1),
-        "backend": jax.default_backend(),
+        "backend": stream["backend"],      # the children's, not this one's
         "stream": stream,
     }
     if not quick:

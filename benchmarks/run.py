@@ -9,13 +9,17 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import sys
 import time
+import traceback
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import paper_figs
     from benchmarks.bridge_scheduling import bridge_scheduling
@@ -41,6 +45,7 @@ def main() -> None:
         benches = {args.only: benches[args.only]}
 
     all_rows = {}
+    failed = []
     print("name,us_per_call,derived")
     for name, fn in benches.items():
         t0 = time.time()
@@ -51,7 +56,9 @@ def main() -> None:
                 label, val = c
                 status_bits.append(f"{label}={val}")
             derived = "; ".join(status_bits)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other entries, then fail the run
+            traceback.print_exc()
+            failed.append(name)
             rows, derived = [], f"ERROR {type(e).__name__}: {e}"
         us = (time.time() - t0) * 1e6
         all_rows[name] = rows
@@ -63,6 +70,8 @@ def main() -> None:
     with open(out, "w") as f:
         json.dump(all_rows, f, indent=1, default=str)
     print(f"# rows -> {out}")
+    if failed:
+        sys.exit(f"benchmark entries failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

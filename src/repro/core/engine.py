@@ -1064,18 +1064,15 @@ def simulate_telescoped(sim: SimState, acc, t0: jnp.ndarray, cfg: SimConfig,
 
 @functools.lru_cache(maxsize=None)
 def _chunk_step_jit(telescope: bool = False):
-    """The jitted per-chunk step, built lazily so the donation decision can
-    read the active backend: donating the (state, accumulator) carry lets
-    XLA reuse their buffers across chunks, but CPU does not implement
-    donation and would warn on every compile.  ``telescope`` swaps the
-    scan for the macro-tick driver — same signature, same carry."""
+    """The jitted per-chunk step.  The (state, accumulator) carry is
+    donated, so XLA reuses its buffers across chunks.  ``telescope`` swaps
+    the scan for the macro-tick driver — same signature, same carry."""
     fn = simulate_telescoped if telescope else simulate_chunk
     def step(sim, acc, t0, policy, params, cfg, n_hosts, n_nodes, chunk):
         return fn(sim, acc, t0, cfg, policy, n_hosts, n_nodes, chunk, params)
-    donate = (0, 1) if jax.default_backend() != "cpu" else ()
     return jax.jit(step, static_argnames=("cfg", "n_hosts", "n_nodes",
                                           "chunk"),
-                   donate_argnums=donate), bool(donate)
+                   donate_argnums=(0, 1))
 
 
 def run_sim_chunked(sim0: SimState, cfg: SimConfig, policy: PolicyParams,
@@ -1096,11 +1093,11 @@ def run_sim_chunked(sim0: SimState, cfg: SimConfig, policy: PolicyParams,
     """
     params = cfg.run_params() if params is None else params
     stats.check_chunk(chunk, int(sim0.containers.status.shape[-1]))
-    step, donated = _chunk_step_jit(telescope)
+    step = _chunk_step_jit(telescope)
     # donation consumes the caller's buffers on the first chunk — keep
     # sim0 valid for reuse (launch/sim.py shares one built state across
     # every policy run)
-    sim = jax.tree.map(jnp.array, sim0) if donated else sim0
+    sim = jax.tree.map(jnp.array, sim0)
     online = stats.online_init()
     t0 = 0
     while t0 < horizon:

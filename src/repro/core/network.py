@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.core.types import NetState
 
-INF = jnp.float32(1e9)
+INF = np.float32(1e9)
 MBPS_TO_KBPS = 125.0  # 1 Mbps = 125 KB/s
 LOCAL_RATE_KBPS = 4.0e6  # same-host "loopback" transfer rate
 # comm-cost weights: single source of truth — every policy's weight vector

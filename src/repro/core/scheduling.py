@@ -51,8 +51,8 @@ from repro.core.types import (
     W_SEL_SUBMIT, WEIGHT_NAMES, PolicyParams, RunParams, SimState,
 )
 
-BIG = jnp.float32(1e18)          # host-score sentinel (infeasible)
-INT_BIG = jnp.int32(2**31 - 1)   # selection-key sentinel (unschedulable)
+BIG = np.float32(1e18)           # host-score sentinel (infeasible)
+INT_BIG = np.int32(2**31 - 1)    # selection-key sentinel (unschedulable)
 
 
 # ---------------------------------------------------------------------------

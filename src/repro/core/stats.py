@@ -115,23 +115,30 @@ def check_chunk(chunk: int, n_containers: int) -> None:
 
 
 def acc_init() -> SummaryAcc:
-    """Zero accumulator (peaks start at 0: every counted series is >= 0)."""
-    z_i = jnp.zeros((), I32)
-    z_f = jnp.zeros((), F32)
+    """Zero accumulator (peaks start at 0: every counted series is >= 0).
+
+    Every leaf is a buffer of its own: the streaming driver donates the
+    accumulator, and one buffer cannot be donated twice."""
+    def z_i():
+        return jnp.zeros((), I32)
+
+    def z_f():
+        return jnp.zeros((), F32)
+
     return SummaryAcc(
-        n_ticks=z_i,
-        sum_util_var=z_f, c_util_var=z_f,
-        sum_mean_util=z_f, c_mean_util=z_f,
-        sum_flow_rate=z_f, c_flow_rate=z_f,
-        w_mean_util=z_f, w_m2_util=z_f,
-        sum_active_flows=z_i, sum_arrivals=z_i, sum_decisions=z_i,
-        sum_migrations=z_i, peak_running=z_i, peak_deployed=z_i,
-        peak_overloaded=z_i, peak_inactive=z_i,
-        sum_soft_comm=z_f, c_soft_comm=z_f,
-        sum_soft_util=z_f, c_soft_util=z_f,
-        sum_soft_n=z_f, c_soft_n=z_f,
-        sum_soft_mig=z_f, c_soft_mig=z_f,
-        sum_soft_mig_n=z_f, c_soft_mig_n=z_f,
+        n_ticks=z_i(),
+        sum_util_var=z_f(), c_util_var=z_f(),
+        sum_mean_util=z_f(), c_mean_util=z_f(),
+        sum_flow_rate=z_f(), c_flow_rate=z_f(),
+        w_mean_util=z_f(), w_m2_util=z_f(),
+        sum_active_flows=z_i(), sum_arrivals=z_i(), sum_decisions=z_i(),
+        sum_migrations=z_i(), peak_running=z_i(), peak_deployed=z_i(),
+        peak_overloaded=z_i(), peak_inactive=z_i(),
+        sum_soft_comm=z_f(), c_soft_comm=z_f(),
+        sum_soft_util=z_f(), c_soft_util=z_f(),
+        sum_soft_n=z_f(), c_soft_n=z_f(),
+        sum_soft_mig=z_f(), c_soft_mig=z_f(),
+        sum_soft_mig_n=z_f(), c_soft_mig_n=z_f(),
     )
 
 

@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SM_NOCHECK as _SM_NOCHECK, shard_map
-
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-tensor absmax int8.  Returns (q, scale)."""
@@ -59,8 +57,8 @@ def pod_compressed_mean(grads: Any, mesh) -> Any:
         def body(gl):
             return compressed_psum_mean(gl, "pod")
 
-        return shard_map(body, mesh=mesh, in_specs=in_spec,
-                         out_specs=in_spec, **_SM_NOCHECK)(g)
+        return jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                             out_specs=in_spec, check_vma=False)(g)
 
     return jax.tree.map(leaf_mean, grads)
 

@@ -22,12 +22,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _col(x, p):
+    """Column ``p`` of a tile as a [bs, 1] column, ``p`` traced.
+
+    A masked min over the lane axis: Mosaic has no lowering for a value
+    slice at a traced lane offset, and the min picks the one unmasked
+    element exactly."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane == p, x, jnp.inf).min(axis=1, keepdims=True)
+
+
+def _row(x, p):
+    """Row ``p`` of a tile as a [1, bs] row, ``p`` traced (see _col)."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(sub == p, x, jnp.inf).min(axis=0, keepdims=True)
+
+
 def _inblock_fw(d):
     """Sequential in-block FW over a [bs, bs] tile (returns updated tile)."""
     bs = d.shape[0]
 
     def body(p, d):
-        return jnp.minimum(d, d[:, p][:, None] + d[p, :][None, :])
+        return jnp.minimum(d, _col(d, p) + _row(d, p))
 
     return jax.lax.fori_loop(0, bs, body, d)
 
@@ -38,10 +54,10 @@ def _minplus(a, b):
     Loops p to keep the VMEM working set at 3 tiles (no [bs,bs,bs]
     intermediate)."""
     bs = a.shape[0]
-    init = a[:, 0][:, None] + b[0, :][None, :]
+    init = _col(a, 0) + _row(b, 0)
 
     def body(p, acc):
-        return jnp.minimum(acc, a[:, p][:, None] + b[p, :][None, :])
+        return jnp.minimum(acc, _col(a, p) + _row(b, p))
 
     return jax.lax.fori_loop(1, bs, body, init)
 
@@ -57,7 +73,7 @@ def _phase2_row_kernel(kk_ref, d_ref, o_ref, *, bs):
     d = d_ref[...]
 
     def body(p, d):
-        return jnp.minimum(d, kk[:, p][:, None] + d[p, :][None, :])
+        return jnp.minimum(d, _col(kk, p) + _row(d, p))
 
     o_ref[...] = jax.lax.fori_loop(0, bs, body, d)
 
@@ -68,7 +84,7 @@ def _phase2_col_kernel(kk_ref, d_ref, o_ref, *, bs):
     d = d_ref[...]
 
     def body(p, d):
-        return jnp.minimum(d, d[:, p][:, None] + kk[p, :][None, :])
+        return jnp.minimum(d, _col(d, p) + _row(kk, p))
 
     o_ref[...] = jax.lax.fori_loop(0, bs, body, d)
 
@@ -99,9 +115,10 @@ def floyd_warshall(A: jnp.ndarray, bs: int = 128,
     def phase1(D, k):
         return pl.pallas_call(
             _phase1_kernel,
+            grid=(1,),         # a windowed block needs a grid on Mosaic
             out_shape=jax.ShapeDtypeStruct((bs, bs), D.dtype),
-            in_specs=[pl.BlockSpec((bs, bs), lambda: (k, k))],
-            out_specs=pl.BlockSpec((bs, bs), lambda: (0, 0)),
+            in_specs=[pl.BlockSpec((bs, bs), lambda _: (k, k))],
+            out_specs=pl.BlockSpec((bs, bs), lambda _: (0, 0)),
             interpret=interpret, name="fw_phase1",
         )(D)
 

@@ -1,4 +1,4 @@
-"""Fused ECMP waterfilling + Mathis cap — Pallas kernel.
+"""ECMP progressive-filling waterfill — Pallas kernel on the scalar core.
 
 The sparse flow engine's hottest loop (`network.max_min_fair_rates_sparse`)
 runs ``n_rounds`` progressive-filling rounds, each of which is a chain of
@@ -9,30 +9,24 @@ XLA ops with HBM round-trips between them:
       -> global min -> freeze mask -> alloc update
       -> segment_sum newly-allocated load -> capacity update
 
-This kernel fuses the WHOLE allocation — all rounds, the leftover-flow
-tail, the Mathis TCP cap, and the final per-link load — into one
-``pallas_call``: every array ([F,4] link ids, [F] flow state, [E] link
-state) is VMEM-resident for the duration, and the only HBM traffic is one
-read of the inputs and one write of (rates [F], load [E]).
+This kernel runs ALL rounds and the leftover-flow tail in one
+``pallas_call`` whose every array lives in SMEM (scalar memory), and does
+the two segment reductions the way the operation is defined: a scalar
+loop over the flattened ``[4F]`` flow slots that scatter-adds into (or
+gathers from) the ``[E]`` link arrays.  That is O(4F + E) work per round —
+the jnp path's own cost, where a vectorised one-hot formulation would pay
+O(4F * E) — and it needs no vector gather or scatter, which Mosaic has no
+general lowering for.
 
-TPU adaptation of the two segment reductions (scatter-add and
-gather-then-min have no vectorized Mosaic lowering):
+Numerics: the per-link sums add the slots in flattened index order, one
+at a time — the order a serial ``segment_sum`` uses — and every per-flow
+step (fair-share divide, freeze rule ``bound <= m * 1.000001 + 1e-6``,
+local-rate min) is the jnp path's own op, so the fair allocation is meant
+to be bit-for-bit the reference's.  The Mathis min and the per-link load
+run after the kernel as the reference's own XLA ops (docs/kernels.md).
 
-* ``per-link sum``  sum_f w[f] * [link(f) == e]  — blocked one-hot
-  contraction: for each (flow-block, link-block) tile, compare the [bf]
-  flattened link ids against the [be] link-id range (a [bf, be] one-hot
-  tile that never leaves registers/VMEM) and reduce over flows.  This is
-  the standard MXU-friendly segment_sum formulation; cost O(F*4*E/8)
-  ops/round instead of a serialized scatter.
-* ``per-flow bound``  min over a flow's <= 4 links of share[link] — the
-  SAME tiling with a min-reduce over the link axis instead of a
-  sum-reduce over the flow axis.
-
-Numerics: counts are exact (sums of {0,1}); float sums (used capacity,
-link load) are tree-reduced per tile instead of scatter-order — a
-documented ~1 ulp association difference vs `jax.ops.segment_sum`
-(docs/kernels.md), which is why the engine keeps the jnp path as the
-default-on-CPU oracle rather than asserting bit-equality.
+SMEM is 1 MiB on a v5e chip; :func:`smem_bytes` gives the footprint, and
+the wrapper refuses shapes that do not fit rather than fail at lowering.
 """
 from __future__ import annotations
 
@@ -41,156 +35,178 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 I32 = jnp.int32
 
-
-def _ceil_to(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
+SMEM_BYTES = 1 << 20          # scalar memory of one TPU v5e core
 
 
-def _waterfill_kernel(links_ref, active_ref, cap_ref, tcp_ref,
-                      rates_ref, load_ref, *,
-                      n_rounds: int, n_links: int, bf: int, be: int,
+def smem_bytes(n_flows: int, n_links: int) -> int:
+    """SMEM the kernel holds for ``n_flows`` flows and ``n_links`` links:
+    the [4F] slot ids, three [F] flow arrays, the [E] capacity input and
+    two [E + 1] link arrays (each padded to 1024-word tiles)."""
+    def words(n):
+        return -(-n // 1024) * 1024
+    return 4 * (words(4 * n_flows) + 3 * words(n_flows) + words(n_links)
+                + 2 * words(n_links + 1))
+
+
+def _waterfill_kernel(lid_ref, active_ref, cap_ref, fair_ref,
+                      cap_rem, acc, bnd, *,
+                      n_rounds: int, n_flows: int, n_links: int,
                       local_rate: float, inf: float):
-    """Single-invocation kernel: all refs whole-array VMEM resident.
+    """Single-invocation scalar kernel.
 
-    ``links`` [F, 4] i32 (pad slots -1), ``active`` [F] i32 mask,
-    ``cap`` [E] f32 link capacity (KB/s), ``tcp`` [F] f32 Mathis ceiling.
-    Outputs: ``rates`` [F] f32, ``load`` [E] f32 per-link allocated KB/s.
+    ``lid`` [4F] i32 flattened slot link ids (``E`` = pad / inactive slot),
+    ``active`` [F] i32, ``cap`` [E] f32 link capacity (KB/s).  Output
+    ``fair`` [F] f32 doubles as the per-flow alloc; ``bnd`` [F] holds the
+    round's bound, or -1 once the flow is frozen.  ``cap_rem`` [E + 1] and
+    ``acc`` [E + 1] are the link arrays; slot ``E`` absorbs pad slots and
+    reads as ``inf``.
     """
-    links = links_ref[...]
-    active = active_ref[...] != 0
-    cap0 = cap_ref[...]
-    tcp = tcp_ref[...]
-    F = links.shape[0]
-    E = n_links
-    F4 = F * 4
+    F, E = n_flows, n_links
 
-    # flattened tiling frame: pad the flow axis to a block multiple and the
-    # link axis to a block multiple; invalid/pad slots point at E_pad (one
-    # past every link block, so they never match a one-hot tile)
-    F4p = _ceil_to(F4, bf)
-    Ep = _ceil_to(E, be)
-    nb_f = F4p // bf
-    nb_e = Ep // be
+    def each(n, body):
+        jax.lax.fori_loop(0, n, lambda i, c: (body(i), c)[1], 0)
 
-    valid = (links >= 0) & active[:, None]                     # [F, 4]
-    w_valid = valid.astype(F32).reshape(F4)
-    lid = jnp.where(valid, links, Ep).reshape(F4)
-    pad_f = F4p - F4
-    if pad_f:
-        w_valid = jnp.concatenate([w_valid, jnp.zeros((pad_f,), F32)])
-        lid = jnp.concatenate([lid, jnp.full((pad_f,), Ep, I32)])
-    cap_p = jnp.concatenate([cap0, jnp.zeros((Ep - E,), F32)]) \
-        if Ep != E else cap0
+    def init_flow(f):
+        act = active_ref[0, f] != 0
+        fair_ref[0, f] = jnp.where(act, F32(local_rate), F32(0.0))
+        # flows with no valid link freeze at the local rate up front
+        any_link = ((lid_ref[0, 4 * f] < E) | (lid_ref[0, 4 * f + 1] < E)
+                    | (lid_ref[0, 4 * f + 2] < E) | (lid_ref[0, 4 * f + 3] < E))
+        bnd[f] = jnp.where(act & ~any_link, F32(-1.0), F32(0.0))
 
-    iota_e = jax.lax.broadcasted_iota(I32, (1, be), 1)          # [1, be]
+    def init_link(e):
+        cap_rem[e] = cap_ref[0, e]
 
-    def per_link_sum(per_flow):
-        """[F] flow weights -> [Ep] per-link sums (blocked one-hot)."""
-        w = (jnp.broadcast_to(per_flow[:, None], (F, 4))
-             .reshape(F4).astype(F32))
-        if pad_f:
-            w = jnp.concatenate([w, jnp.zeros((pad_f,), F32)])
-        w = w * w_valid
+    each(F, init_flow)
+    each(E, init_link)
+    cap_rem[E] = F32(0.0)
 
-        def ebody(eb, acc):
-            ids = eb * be + iota_e                              # [1, be]
+    def unfrozen(f):
+        return (active_ref[0, f] != 0) & (bnd[f] >= 0.0)
 
-            def fbody(fb, part):
-                l_blk = jax.lax.dynamic_slice(lid, (fb * bf,), (bf,))
-                w_blk = jax.lax.dynamic_slice(w, (fb * bf,), (bf,))
-                oh = l_blk[:, None] == ids                      # [bf, be]
-                return part + jnp.where(oh, w_blk[:, None], 0.0).sum(0)
+    def zero_acc():
+        def body(e):
+            acc[e] = F32(0.0)
+        each(E + 1, body)
 
-            part = jax.lax.fori_loop(0, nb_f, fbody,
-                                     jnp.zeros((be,), F32))
-            return jax.lax.dynamic_update_slice(acc, part, (eb * be,))
+    def scatter(weight):
+        """acc[lid[i]] += weight(i // 4) in slot order (the segment_sum)."""
+        def body(f):
+            w = weight(f)
+            for s in range(4):
+                l = lid_ref[0, 4 * f + s]
+                acc[l] = acc[l] + w
+        each(F, body)
 
-        return jax.lax.fori_loop(0, nb_e, ebody, jnp.zeros((Ep,), F32))
+    def fair_share():
+        """acc: unfrozen-flow counts -> per-link fair share (inf if none)."""
+        zero_acc()
+        scatter(lambda f: jnp.where(unfrozen(f), F32(1.0), F32(0.0)))
 
-    def fair_bound(unfrozen, cap_rem):
-        """Per-flow fair-share bound: min over its valid links of
-        cap_rem[e] / count[e] (INF for flows with no valid link)."""
-        cnt = per_link_sum(unfrozen.astype(F32))
-        share = jnp.where(cnt > 0, cap_rem / jnp.maximum(cnt, 1.0), inf)
+        def body(e):
+            cnt = acc[e]
+            acc[e] = jnp.where(cnt > 0.0,
+                               cap_rem[e] / jnp.maximum(cnt, F32(1.0)),
+                               F32(inf))
+        each(E, body)
+        acc[E] = F32(inf)
 
-        def fbody(fb, bnd):
-            l_blk = jax.lax.dynamic_slice(lid, (fb * bf,), (bf,))
-            v_blk = jax.lax.dynamic_slice(w_valid, (fb * bf,), (bf,)) > 0
-
-            def ebody(eb, b_blk):
-                ids = eb * be + iota_e
-                sh = jax.lax.dynamic_slice(share, (eb * be,), (be,))
-                oh = (l_blk[:, None] == ids) & v_blk[:, None]
-                cand = jnp.where(oh, sh[None, :], inf).min(1)   # [bf]
-                return jnp.minimum(b_blk, cand)
-
-            b_blk = jax.lax.fori_loop(0, nb_e, ebody,
-                                      jnp.full((bf,), inf, F32))
-            return jax.lax.dynamic_update_slice(bnd, b_blk, (fb * bf,))
-
-        b4 = jax.lax.fori_loop(0, nb_f, fbody, jnp.full((F4p,), inf, F32))
-        return b4[:F4].reshape(F, 4).min(1)                     # [F]
-
-    # --- progressive filling, identical round structure to the jnp ref ---
-    alloc0 = jnp.where(active, local_rate, 0.0)
-    frozen0 = active & ~valid.any(1)          # no-link flows: local rate
+    def bound_of(f):
+        b = acc[lid_ref[0, 4 * f]]
+        for s in range(1, 4):
+            b = jnp.minimum(b, acc[lid_ref[0, 4 * f + s]])
+        return b
 
     def round_body(_, carry):
-        alloc, frozen, cap_rem = carry
-        unfrozen = active & ~frozen
-        bound = jnp.where(unfrozen, fair_bound(unfrozen, cap_rem), inf)
-        m = bound.min()
-        newly = unfrozen & (bound <= m * 1.000001 + 1e-6)
-        new_alloc = jnp.where(newly, jnp.minimum(bound, local_rate), alloc)
-        used = per_link_sum(jnp.where(newly, new_alloc, 0.0))
-        return (new_alloc, frozen | newly,
-                jnp.maximum(cap_rem - used, 0.0))
+        fair_share()
 
-    alloc, frozen, cap_rem = jax.lax.fori_loop(
-        0, n_rounds, round_body, (alloc0, frozen0, cap_p))
+        def bound_pass(f, m):
+            live = unfrozen(f)
+            b = jnp.where(live, bound_of(f), F32(inf))
+            bnd[f] = jnp.where(live, b, bnd[f])
+            return jnp.minimum(m, b)
+
+        m = jax.lax.fori_loop(0, F, bound_pass, F32(inf))
+        thr = m * F32(1.000001) + F32(1e-6)
+
+        def freeze(f):
+            b = bnd[f]
+            newly = unfrozen(f) & (b <= thr)
+            fair_ref[0, f] = jnp.where(newly, jnp.minimum(b, F32(local_rate)),
+                                    fair_ref[0, f])
+            bnd[f] = jnp.where(newly, F32(-1.0), b)
+            return jnp.where(newly, fair_ref[0, f], F32(0.0))
+
+        # the newly-frozen weight must be read before the freeze marks the
+        # flow, so freeze inside the scatter's per-flow step
+        zero_acc()
+        scatter(freeze)
+
+        def spend(e):
+            cap_rem[e] = jnp.maximum(cap_rem[e] - acc[e], F32(0.0))
+        each(E, spend)
+        return carry
+
+    jax.lax.fori_loop(0, n_rounds, round_body, 0)
 
     # leftover tail (more bottleneck levels than rounds): current fair share
-    leftover = active & ~frozen
-    tail = jnp.minimum(fair_bound(leftover, cap_rem), local_rate)
-    alloc = jnp.where(leftover, tail, alloc)
-    fair = jnp.where(active, alloc, 0.0)
+    fair_share()
 
-    # fused Mathis arm + final link load
-    rates = jnp.minimum(fair, tcp) * active
-    rates_ref[...] = rates
-    load_ref[...] = per_link_sum(rates)[:E]
+    def tail(f):
+        left = unfrozen(f)
+        fair_ref[0, f] = jnp.where(left,
+                                jnp.minimum(bound_of(f), F32(local_rate)),
+                                fair_ref[0, f])
+    each(F, tail)
 
 
-@functools.partial(jax.jit, static_argnames=("n_rounds", "bf", "be",
-                                             "interpret", "local_rate",
-                                             "inf"))
+@functools.partial(jax.jit, static_argnames=("n_rounds", "interpret",
+                                             "local_rate", "inf"))
 def seg_waterfill(links: jnp.ndarray, active: jnp.ndarray,
                   link_bw_kbps: jnp.ndarray, tcp_cap: jnp.ndarray,
-                  n_rounds: int = 8, bf: int = 2048, be: int = 256,
-                  interpret: bool = True, local_rate: float = 4.0e6,
-                  inf: float = 1e9):
-    """Fused max-min-fair + Mathis allocation.  Returns (rates [F], load [E]).
+                  n_rounds: int = 8, interpret: bool = True,
+                  local_rate: float = 4.0e6, inf: float = 1e9):
+    """Max-min-fair + Mathis allocation.  Returns (rates [F], load [E]).
 
     ``links`` [F, 4] i32 ECMP link ids (-1 padded), ``active`` [F] bool/i32,
     ``link_bw_kbps`` [E] f32, ``tcp_cap`` [F] f32 per-flow Mathis ceiling
-    (use ``inf`` for loss-free paths).  ``bf``/``be`` tile the flattened
-    flow-slot and link axes; [bf, be] is the one-hot working tile.
+    (use ``inf`` for loss-free paths).  The progressive filling runs in
+    the kernel; the Mathis min and the per-link load are the reference's
+    own ops on its output.
     """
     F = links.shape[0]
     E = link_bw_kbps.shape[0]
-    bf = min(bf, _ceil_to(F * 4, 8))
-    be = min(be, _ceil_to(E, 8))
+    need = smem_bytes(F, E)
+    if need > SMEM_BYTES:
+        raise ValueError(
+            f"seg_waterfill needs {need} B of SMEM for {F} flows x {E} "
+            f"links, over the {SMEM_BYTES} B a v5e core has; run this "
+            f"shape with waterfill_kernel='off'")
+    active = active.astype(bool)
+    valid = (links >= 0) & active[:, None]
+    seg = jnp.where(valid, links, E).astype(I32)                # [F, 4]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     kernel = functools.partial(
-        _waterfill_kernel, n_rounds=n_rounds, n_links=E, bf=bf, be=be,
+        _waterfill_kernel, n_rounds=n_rounds, n_flows=F, n_links=E,
         local_rate=local_rate, inf=inf)
-    return pl.pallas_call(
+    fair = pl.pallas_call(
         kernel,
-        out_shape=(jax.ShapeDtypeStruct((F,), jnp.float32),
-                   jax.ShapeDtypeStruct((E,), jnp.float32)),
+        out_shape=jax.ShapeDtypeStruct((1, F), F32),
+        in_specs=[smem, smem, smem],
+        out_specs=smem,
+        scratch_shapes=[pltpu.SMEM((E + 1,), F32),
+                        pltpu.SMEM((E + 1,), F32),
+                        pltpu.SMEM((F,), F32)],
         interpret=interpret, name="seg_waterfill",
-    )(links.astype(I32), active.astype(I32),
-      link_bw_kbps.astype(F32), tcp_cap.astype(F32))
+    )(seg.reshape(1, 4 * F), active.astype(I32).reshape(1, F),
+      link_bw_kbps.astype(F32).reshape(1, E))[0]
+    rates = jnp.minimum(fair, tcp_cap) * active
+    w = (rates[:, None] * (links >= 0).astype(F32)).reshape(-1)
+    lseg = jnp.where(links >= 0, links, E).reshape(-1)
+    load = jax.ops.segment_sum(w, lseg, num_segments=E + 1)[:E]
+    return rates, load

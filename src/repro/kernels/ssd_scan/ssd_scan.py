@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _ssd_kernel(xs_ref, b_ref, c_ref, dt_ref, alog_ref, h0_ref,
                 y_ref, hout_ref, h_ref, *, Q):
@@ -113,7 +109,7 @@ def ssd_chunked_kernel(xs, Bm, Cm, dt, A_log, Q: int = 256, h0=None,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="ssd_scan",
     )(xs_t, Bm, Cm, dt_t, A_log.reshape(H, 1), h0)

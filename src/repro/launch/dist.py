@@ -554,8 +554,23 @@ def _log_tail(out_dir: str, i: int, lines: int = 30) -> str:
         return f"--- {path}: unreadable ---"
 
 
+def _check_worker_platform() -> None:
+    """Workers run on this process's platform, and only the CPU can be
+    shared with them: a process that has touched an accelerator holds it,
+    so a worker that needs it would fail or hang.  Refuse up front."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"the multi-process sweep fabric cannot run on a {backend} "
+            "host: this launcher process already holds the accelerator, so "
+            "its workers could not claim it, and running them on the CPU "
+            "instead would report CPU results for an accelerator run.  Use "
+            "the in-process sweep (repro.launch.sweep, which shards cells "
+            "over every local device), or launch with JAX_PLATFORMS=cpu.")
+
+
 def _spawn_and_wait(spec_path: str, out_dir: str, num_procs: int,
-                    devices_per_proc: int, dist_init: bool, force_cpu: bool,
+                    devices_per_proc: int, dist_init: bool,
                     timeout_s: float) -> None:
     coord = f"127.0.0.1:{_free_port()}" if dist_init else None
     handout = f"127.0.0.1:{_free_port()}"
@@ -566,14 +581,15 @@ def _spawn_and_wait(spec_path: str, out_dir: str, num_procs: int,
             env = dict(os.environ)
             env["PYTHONPATH"] = (str(_SRC) + os.pathsep
                                  + env.get("PYTHONPATH", ""))
-            if force_cpu:
-                env["JAX_PLATFORMS"] = "cpu"
-                flags = re.sub(
-                    r"--xla_force_host_platform_device_count=\d+", "",
-                    env.get("XLA_FLAGS", ""))
-                env["XLA_FLAGS"] = (
-                    flags + " --xla_force_host_platform_device_count="
-                    + str(devices_per_proc)).strip()
+            # the launcher's own platform (_check_worker_platform), with
+            # each worker's CPU devices forced to devices_per_proc
+            env["JAX_PLATFORMS"] = "cpu"
+            flags = re.sub(
+                r"--xla_force_host_platform_device_count=\d+", "",
+                env.get("XLA_FLAGS", ""))
+            env["XLA_FLAGS"] = (
+                flags + " --xla_force_host_platform_device_count="
+                + str(devices_per_proc)).strip()
             cmd = [sys.executable, "-m", "repro.launch.dist_worker",
                    "--spec", spec_path, "--out", out_dir,
                    "--process-id", str(i),
@@ -617,12 +633,14 @@ DistRun = collections.namedtuple("DistRun", "finals summary metas wall_s")
 
 
 def run_spec(spec: GridSpec, *, num_procs: int, out_dir: str | None = None,
-             dist_init: bool = True, force_cpu: bool = True,
+             dist_init: bool = True,
              timeout_s: float = 900.0) -> DistRun:
     """Spawn ``num_procs`` workers over ``spec``, join, merge.  With a
     persistent ``out_dir`` a rerun resumes (completed slabs are skipped by
     the coordinator and merged from disk); the default is a temp dir
-    cleaned up after the merge."""
+    cleaned up after the merge.  Refuses on an accelerator host
+    (``_check_worker_platform``)."""
+    _check_worker_platform()
     tmp = None
     if out_dir is None:
         tmp = tempfile.TemporaryDirectory(prefix="dist_sweep_")
@@ -633,8 +651,7 @@ def run_spec(spec: GridSpec, *, num_procs: int, out_dir: str | None = None,
         spec.save(spec_path)
         t0 = time.time()
         _spawn_and_wait(spec_path, out_dir, num_procs,
-                        spec.devices_per_proc, dist_init, force_cpu,
-                        timeout_s)
+                        spec.devices_per_proc, dist_init, timeout_s)
         finals, summary, metas = merge_out_dir(spec, out_dir)
         return DistRun(finals, summary, metas, round(time.time() - t0, 2))
     finally:
@@ -652,7 +669,7 @@ def make_dist_fn(cfg: SimConfig, scenarios: Sequence[ScenarioSpec],
                  overlap: bool | None = None,
                  plan: ExecPlan | None = None,
                  out_dir: str | None = None, dist_init: bool = True,
-                 force_cpu: bool = True, timeout_s: float = 900.0):
+                 timeout_s: float = 900.0):
     """Drop-in sweep callable (``fn(sims, pols, rps) -> (finals,
     summary)`` with ``fn._cache_size``/``fn.n_devices``, like
     ``make_stream_fn``) that runs the grid MULTI-PROCESS.  Execution
@@ -690,8 +707,7 @@ def make_dist_fn(cfg: SimConfig, scenarios: Sequence[ScenarioSpec],
             raise ValueError("policy weights differ from the dist spec — "
                              "workers rebuild the grid from the spec")
         run = run_spec(spec, num_procs=num_procs, out_dir=out_dir,
-                       dist_init=dist_init, force_cpu=force_cpu,
-                       timeout_s=timeout_s)
+                       dist_init=dist_init, timeout_s=timeout_s)
         state["metas"] = run.metas
         fn.last_run = run
         return run.finals, run.summary
@@ -714,7 +730,6 @@ def run_dist_sweep(policies: Sequence[str] | None = None,
                    overlap: bool | None = None,
                    plan: ExecPlan | None = None,
                    out_dir: str | None = None, dist_init: bool = True,
-                   force_cpu: bool = True,
                    timeout_s: float = 900.0) -> SweepResult:
     """The multi-process twin of ``sweep.run_sweep`` — always streaming
     (a missing ``plan.chunk`` defaults to the largest bound-safe chunk).
@@ -739,8 +754,7 @@ def run_dist_sweep(policies: Sequence[str] | None = None,
                           slab=plan.slab, overlap=plan.overlap,
                           devices_per_proc=plan.devices_per_proc)
     run = run_spec(spec, num_procs=plan.procs, out_dir=out_dir,
-                   dist_init=dist_init, force_cpu=force_cpu,
-                   timeout_s=timeout_s)
+                   dist_init=dist_init, timeout_s=timeout_s)
     return SweepResult(
         policies=policies, scenarios=scenarios, seeds=tuple(seeds),
         finals=run.finals, metrics=None, summary=run.summary,

@@ -36,6 +36,8 @@ def parse_args(argv):
 
 def main(argv=None) -> None:
     a = parse_args(sys.argv[1:] if argv is None else list(argv))
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if not a.no_dist_init:
         if not a.coordinator:
             raise SystemExit("--coordinator required unless --no-dist-init")

@@ -9,25 +9,19 @@ from __future__ import annotations
 import jax
 
 
-def compat_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the jax version has them
-    (``jax.sharding.AxisType`` only exists in newer releases).
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
 
     ``devices`` optionally pins an explicit device sequence (e.g. a subset,
     or ``jax.local_devices()`` under ``jax.distributed`` where the global
     ``jax.devices()`` list contains non-addressable devices) — the sweep
-    fabric's ``grid_mesh`` builds through here so there is exactly ONE
-    AxisType-compat mesh constructor in the repo.
+    fabric's ``grid_mesh`` builds through here, so the repo has one mesh
+    constructor.
     """
     kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes), **kwargs)
-    return jax.make_mesh(shape, axes, **kwargs)  # older jax: Auto only
-
-
-_mesh = compat_mesh
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,16 +33,16 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh with the production axis names (CPU tests)."""
-    return _mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def make_mesh_for(n_devices: int, model_parallel: int = 1):
     """Generic mesh over however many devices are actually present."""
     assert n_devices % model_parallel == 0
-    return _mesh((n_devices // model_parallel, model_parallel),
+    return auto_mesh((n_devices // model_parallel, model_parallel),
                  ("data", "model"))

@@ -130,8 +130,6 @@ def raw_costs(compiled, hlo_text: str) -> Dict[str, float]:
     """Per-device (flops, bytes, collective bytes + breakdown) of one
     compiled executable — no loop-body correction (see dryrun probes)."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):          # older jax returns [dict]
-        cost = cost[0]
     coll = collective_bytes(hlo_text)
     return {
         "flops": float(cost.get("flops", 0.0)),

@@ -28,6 +28,7 @@ from repro.core import (ExecPlan, SimConfig, build_paper_hosts,
                         list_policies, paper_workload, run_sim, scaled_hosts,
                         summarize, to_csv, trace_workload)
 from repro.core.report import json_clean
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.execargs import add_exec_args
 
 
@@ -138,6 +139,7 @@ def main() -> None:
                          "e.g. 'cross_leaf=0.5,row_coloc=0.3' "
                          "(types.WEIGHT_NAMES; not valid with --policy all)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     weights = parse_weights(args.weights)
     if weights and args.policy == "all":

@@ -4,14 +4,18 @@ The paper's headline use case is comparing scheduling strategies under
 varying network conditions (Figs 4-10).  With policies as weight vectors
 and runtime parameters as data (``PolicyParams``/``RunParams``), the whole
 evaluation grid is one ``vmap`` over ONE flattened batch axis of P*S*N
-cells, jitted exactly once — and that single axis is sharded across every
-available device with a ``NamedSharding`` (each device integrates its
-slice of cells independently; there is no cross-cell communication):
+cells, jitted exactly once — and that single axis is split across every
+available device with a ``shard_map`` (each device integrates its slice of
+cells independently; there is no cross-cell communication):
 
     policies [P] --+
     scenarios [S] --+--> flatten [P*S*N] --vmap--> jit --> [P, S, N]
     seeds     [N] --+         |
-                              +-- NamedSharding over the 'grid' mesh axis
+                              +-- shard_map over the 'grid' mesh axis
+
+The split is a ``shard_map`` and not a sharding constraint because the
+tick's Pallas kernels cannot be partitioned by XLA: each device runs the
+vmapped cell program on its own cells, kernels included.
 
     PYTHONPATH=src python -m repro.launch.sweep --policies all \\
         --seeds 2 --horizon 120 --table avg_runtime --out sweep.json
@@ -40,8 +44,9 @@ from repro.core.scenario import (ScenarioSpec, build_scenarios,
 from repro.core.scheduling import validate_weights
 from repro.core.types import (ExecPlan, OnlineSummary, PolicyParams,
                               RunParams, SimState, TickMetrics)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.execargs import add_exec_args
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import auto_mesh
 
 I32 = jnp.int32
 
@@ -89,8 +94,8 @@ def grid_mesh(devices=None) -> Mesh | None:
     ``jax.distributed`` the global list contains other processes'
     non-addressable devices, and the sweep fabric's cross-host story is
     slab-per-process with a host-side reduction (``repro.launch.dist``),
-    never a global-SPMD program.  Built through ``mesh.compat_mesh`` —
-    the repo's one AxisType-compat mesh constructor.
+    never a global-SPMD program.  Built through ``mesh.auto_mesh`` —
+    the repo's one mesh constructor.
     """
     if devices is None:
         devices = jax.local_devices()
@@ -99,7 +104,25 @@ def grid_mesh(devices=None) -> Mesh | None:
     devices = list(devices)
     if len(devices) <= 1:
         return None
-    return compat_mesh((len(devices),), ("grid",), devices=devices)
+    return auto_mesh((len(devices),), ("grid",), devices=devices)
+
+
+def _per_device(fn, mesh, sim_tree, out_sims: bool):
+    """Run the vmapped cell program ``fn(sims, cell_args)`` on each
+    device's slice of the flattened cell axis (``shard_map``), topology
+    leaves replicated.  ``out_sims`` says whether the first output is a
+    ``SimState`` whose topology leaves stay unbatched.  ``mesh=None`` (one
+    device) returns ``fn`` unchanged."""
+    if mesh is None:
+        return fn
+    cells = PartitionSpec("grid")
+    flat, treedef = jax.tree_util.tree_flatten_with_path(sim_tree)
+    sim_specs = jax.tree_util.tree_unflatten(
+        treedef, [PartitionSpec() if _is_static_leaf(p) else cells
+                  for p, _ in flat])
+    out_specs = (sim_specs, cells) if out_sims else cells
+    return jax.shard_map(fn, mesh=mesh, in_specs=(sim_specs, cells),
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_sweep_fn(cfg: SimConfig, n_hosts: int, n_nodes: int, horizon: int,
@@ -116,8 +139,8 @@ def make_sweep_fn(cfg: SimConfig, n_hosts: int, n_nodes: int, horizon: int,
     flattened to a single [P*S*N] batch inside the jitted function
     (branch-free scoring makes the policy axis pure data like the others —
     no ``lax.switch`` evaluating every branch per cell).  With more than
-    one device the flattened axis carries a ``NamedSharding`` over the
-    1-axis ``grid`` mesh, padded to a device multiple by repeating cells
+    one device the flattened axis is split over the 1-axis ``grid`` mesh
+    (``shard_map``), padded to a device multiple by repeating cells
     (the pad cells are sliced off before reshaping back to [P, S, N]);
     cells are independent, so sharded == unsharded bit-for-bit
     (``tests/test_sweep_sharded.py``).
@@ -172,9 +195,6 @@ def _make_grid(cfg: SimConfig, n_hosts: int, n_nodes: int, horizon: int,
         if pad:
             idx = jnp.arange(B + pad) % B
             args = jax.tree.map(lambda x: x[idx], args)
-        if mesh is not None:
-            args = jax.lax.with_sharding_constraint(
-                args, NamedSharding(mesh, PartitionSpec("grid")))
         # de-batch the topology leaves (every cell carries the same
         # tables; uniformity is checked host-side in fn below) and build
         # the matching in_axes tree: 0 everywhere, None at the statics.
@@ -185,8 +205,10 @@ def _make_grid(cfg: SimConfig, n_hosts: int, n_nodes: int, horizon: int,
         sim_axes = jtu.tree_unflatten(
             treedef, [None if _is_static_leaf(p) else 0
                       for p, x in flat_sims])
-        out = jax.vmap(cell, in_axes=(sim_axes, 0, 0))(
-            sim_arg, args[1], args[2])
+        run = jax.vmap(lambda s, rest: cell(s, *rest),
+                       in_axes=(sim_axes, (0, 0)))
+        out = _per_device(run, mesh, sim_arg, out_sims=False)(
+            sim_arg, (args[1], args[2]))
         if pad:
             out = jax.tree.map(lambda x: x[:B], out)
         return jax.tree.map(
@@ -366,8 +388,8 @@ def make_stream_fn(cfg: SimConfig, n_hosts: int, n_nodes: int, horizon: int,
     the per-cell link-param application rides a ``t0 == 0`` cond, and the
     static topology leaves stay unbatched through the vmap in BOTH
     directions (``in_axes``/``out_axes`` None) so every slab re-enters the
-    same compiled program.  On non-CPU backends the (state, accumulator)
-    carry is donated, so a slab's device footprint never doubles.
+    same compiled program.  The (state, accumulator) carry is donated, so
+    a slab's device footprint never doubles.
 
     The driver is OVERLAPPED (PR 8): jax dispatch is asynchronous, so the
     loop never blocks between chunks — per-chunk accumulators are kept as
@@ -399,27 +421,19 @@ def make_stream_fn(cfg: SimConfig, n_hosts: int, n_nodes: int, horizon: int,
     cell_fn = simulate_telescoped if telescope else simulate_chunk
 
     def step(sims, accs, pols, rps, t0, csz):
-        if mesh is not None:
-            spec = NamedSharding(mesh, PartitionSpec("grid"))
-            shard = lambda x: jax.lax.with_sharding_constraint(x, spec)
-            flat, treedef = jtu.tree_flatten_with_path(sims)
-            sims = jtu.tree_unflatten(
-                treedef, [x if _is_static_leaf(p) else shard(x)
-                          for p, x in flat])
-            accs, pols, rps = jax.tree.map(shard, (accs, pols, rps))
-
-        def cell(sim, acc, pol, rp):
+        def cell(sim, rest):
+            acc, pol, rp = rest
             return cell_fn(sim, acc, t0, cfg, pol, n_hosts, n_nodes,
                            csz, rp)
 
         flat, treedef = jtu.tree_flatten_with_path(sims)
         sim_axes = jtu.tree_unflatten(
             treedef, [None if _is_static_leaf(p) else 0 for p, _ in flat])
-        return jax.vmap(cell, in_axes=(sim_axes, 0, 0, 0),
-                        out_axes=(sim_axes, 0))(sims, accs, pols, rps)
+        run = jax.vmap(cell, in_axes=(sim_axes, 0), out_axes=(sim_axes, 0))
+        return _per_device(run, mesh, sims, out_sims=True)(
+            sims, (accs, pols, rps))
 
-    donate = (0, 1) if jax.default_backend() != "cpu" else ()
-    jstep = jax.jit(step, static_argnames=("csz",), donate_argnums=donate)
+    jstep = jax.jit(step, static_argnames=("csz",), donate_argnums=(0, 1))
 
     def slab_cells(B: int) -> int:
         """Wrap-padded device-multiple slab size for a B-cell grid."""
@@ -647,18 +661,17 @@ def _run_sim_vmapped_jit(sims, cfg, policy, params, n_hosts, n_nodes,
 
 @functools.lru_cache(maxsize=None)
 def _vmapped_chunk_step_jit(telescope: bool = False):
-    """Jitted seed-batched chunk step (lazy: the donation decision reads
-    the backend, exactly like ``engine._chunk_step_jit``)."""
+    """Jitted seed-batched chunk step with a donated carry (like
+    ``engine._chunk_step_jit``)."""
     fn = simulate_telescoped if telescope else simulate_chunk
 
     def step(sims, accs, t0, policy, params, cfg, n_hosts, n_nodes, chunk):
         return jax.vmap(
             lambda s, a: fn(s, a, t0, cfg, policy, n_hosts,
                             n_nodes, chunk, params))(sims, accs)
-    donate = (0, 1) if jax.default_backend() != "cpu" else ()
     return jax.jit(step, static_argnames=("cfg", "n_hosts", "n_nodes",
                                           "chunk"),
-                   donate_argnums=donate), bool(donate)
+                   donate_argnums=(0, 1))
 
 
 def run_sim_vmapped(sims: SimState, cfg: SimConfig, policy: PolicyParams,
@@ -688,8 +701,8 @@ def run_sim_vmapped(sims: SimState, cfg: SimConfig, policy: PolicyParams,
     chunk = chunk or horizon
     N = sims.t.shape[0]
     stats.check_chunk(chunk, int(sims.containers.status.shape[-1]))
-    step, donated = _vmapped_chunk_step_jit(telescope)
-    cur = jax.tree.map(jnp.array, sims) if donated else sims
+    step = _vmapped_chunk_step_jit(telescope)
+    cur = jax.tree.map(jnp.array, sims)      # the caller's sims survive
     online = stats.online_init((N,))
     t0 = 0
     while t0 < horizon:
@@ -725,6 +738,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compile_cache()
 
     policies = (list_policies() if args.policies == "all"
                 else args.policies.split(","))

@@ -5,8 +5,7 @@ With branch-free scoring a policy IS a point in weight space
 sample W weight vectors, stack them on the sweep's policy axis, and run
 the whole W x scenario x seed population as ONE jit — the same
 ``make_sweep_fn`` program the policy sweep uses, with weights instead of
-named policies on the batch axis (and the same ``NamedSharding`` across
-devices).  This is the ROADMAP "learned netaware weights" item in its
+named policies on the batch axis (and the same split across devices).  This is the ROADMAP "learned netaware weights" item in its
 simplest honest form: random (or per-dimension grid) search, one
 compilation, a ranked best-weights table via ``report.tune_table``.
 
@@ -32,6 +31,7 @@ from repro.core.scenario import ScenarioSpec, build_scenarios
 from repro.core.scheduling import validate_weights, weight_index
 from repro.core.types import (NUM_POLICY_WEIGHTS, WEIGHT_NAMES, ExecPlan,
                               PolicyParams)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.execargs import add_exec_args
 from repro.launch.sweep import make_grad_fn, make_stream_fn, make_sweep_fn
 
@@ -527,6 +527,7 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="write best weights + ranked samples as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = SimConfig(horizon=args.horizon)
     plan = ExecPlan.from_args(args)

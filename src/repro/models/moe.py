@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SM_NOCHECK as _SM_NOCHECK, shard_map
-
 from repro.models.layers import BF16, F32, init_dense
 
 MODEL_AXIS = "model"
@@ -225,14 +223,14 @@ def moe_layer_ep(params, x, cfg, mesh, data_axes: tuple):
         fn = functools.partial(_ep_shard, cfg=cfg,
                                n_model=mesh.shape[MODEL_AXIS],
                                data_axes=data_axes)
-    y = shard_map(
+    y = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec,
                   P(MODEL_AXIS, "data", None),
                   P(MODEL_AXIS, "data", None),
                   P(MODEL_AXIS, None, "data")),
         out_specs=tok_spec,
-        **_SM_NOCHECK,
+        check_vma=False,
     )(x, topw.astype(x.dtype), topi,
       params["w_gate"], params["w_up"], params["w_down"])
     y = y.astype(x.dtype)

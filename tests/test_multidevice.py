@@ -16,7 +16,7 @@ import dataclasses, json
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_reduced
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import auto_mesh
 from repro.models import sharding as shd
 from repro.train.optimizer import OptimizerConfig
 from repro.train.step import init_train_state, make_train_step
@@ -37,7 +37,7 @@ for arch, overrides in [
     for name, cfg, mesh in [
         ("1dev", base, None),
         ("8dev", dataclasses.replace(base, **overrides),
-         compat_mesh((2, 4), ("data", "model"))),
+         auto_mesh((2, 4), ("data", "model"))),
     ]:
         state = init_train_state(cfg, jax.random.PRNGKey(0))
         dp = ("data",)
@@ -84,7 +84,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_reduced
 from repro.distributed import checkpoint as ckpt
-from repro.launch.mesh import compat_mesh
+from repro.launch.mesh import auto_mesh
 from repro.models import sharding as shd
 from repro.train.optimizer import OptimizerConfig
 from repro.train.step import init_train_state, make_train_step
@@ -103,7 +103,7 @@ def sharded_state(mesh, state):
     return jax.device_put(state, sh), sh
 
 # "2-pod" mesh: (pod=2, data=2, model=2); train 2 steps; checkpoint
-mesh_big = compat_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh_big = auto_mesh((2, 2, 2), ("pod", "data", "model"))
 state = init_train_state(cfg, jax.random.PRNGKey(0))
 with mesh_big:
     state, _ = sharded_state(mesh_big, state)
@@ -118,7 +118,7 @@ ckpt.save_checkpoint(d, state, 2)
 
 # elastic downsize: restore the same checkpoint onto a 1-pod (2,2) mesh
 # (pod lost), continue training — the DCSim fault plan's 'elastic_downsize'
-mesh_small = compat_mesh((2, 2), ("data", "model"))
+mesh_small = auto_mesh((2, 2), ("data", "model"))
 with mesh_small:
     fresh = init_train_state(cfg, jax.random.PRNGKey(0))
     _, sh_small = sharded_state(mesh_small, fresh)

@@ -268,6 +268,16 @@ def test_merge_rejects_foreign_slab_plan(tmp_path):
         dist.merge_out_dir(other, out)
 
 
+def test_fabric_refuses_accelerator_host(tmp_path, monkeypatch):
+    """Workers cannot claim a chip the launcher holds, and CPU workers
+    would report CPU results for a chip run: refuse before spawning."""
+    monkeypatch.setattr(dist.jax, "default_backend", lambda: "tpu")
+    spec = tiny_spec(tiny_cfg())
+    with pytest.raises(RuntimeError, match="cannot run on a tpu host"):
+        dist.run_spec(spec, num_procs=1, out_dir=str(tmp_path / "run"))
+    assert not (tmp_path / "run").exists()
+
+
 # ---------------------------------------------------------------------------
 # The oracle: 2 spawned processes x 2 forced CPU devices, jax.distributed
 # ---------------------------------------------------------------------------
