@@ -621,11 +621,12 @@ def make_refresh_fn(cfg: SimConfig, policy: PolicyParams, params: RunParams,
     use_fw_kernel = resolve_kernel(cfg.delay_kernel)
 
     def refresh(net):
-        return network.update_delay_matrix(
-            net, n_hosts, n_nodes, mode=cfg.delay_mode,
-            use_kernel=use_fw_kernel, q_coef=params.queue_coef,
-            util_weight=policy.weights[W_UTIL],
-            cross_leaf_ms=policy.weights[W_CROSS_LEAF])
+        with jax.named_scope("refresh"):
+            return network.update_delay_matrix(
+                net, n_hosts, n_nodes, mode=cfg.delay_mode,
+                use_kernel=use_fw_kernel, q_coef=params.queue_coef,
+                util_weight=policy.weights[W_UTIL],
+                cross_leaf_ms=policy.weights[W_CROSS_LEAF])
 
     return refresh
 
@@ -650,16 +651,27 @@ def make_tick_ext(cfg: SimConfig, policy: PolicyParams, params: RunParams,
     use_wf_kernel = cfg.sparse_flows and resolve_kernel(cfg.waterfill_kernel)
 
     def tick_ext(sim: SimState, tt: jnp.ndarray):
-        sim, n_arrived = phase_arrive(sim)
-        sim, soft = phase_schedule_soft(sim, cfg, policy, params)
+        # each phase runs under a named scope of its own name, so a
+        # profiler trace attributes every device op to its phase
+        # (docs/perf.md, "Profiling a run"); scopes are metadata only
+        with jax.named_scope("arrive"):
+            sim, n_arrived = phase_arrive(sim)
+        with jax.named_scope("schedule"):
+            sim, soft = phase_schedule_soft(sim, cfg, policy, params)
         mid = sim.containers          # the state phase_flows consumes
-        sim, comm_rates, mig_rates, flow_active, all_rates = \
-            phase_flows(sim, cfg, use_kernel=use_wf_kernel)
-        sim = phase_communicate(sim, cfg, comm_rates)
-        sim = phase_migrate(sim, cfg, mig_rates)
-        sim = phase_execute(sim, cfg)
-        sim = phase_complete(sim)
-        sim = phase_cost(sim)
+        with jax.named_scope("flows"):
+            sim, comm_rates, mig_rates, flow_active, all_rates = \
+                phase_flows(sim, cfg, use_kernel=use_wf_kernel)
+        with jax.named_scope("communicate"):
+            sim = phase_communicate(sim, cfg, comm_rates)
+        with jax.named_scope("migrate"):
+            sim = phase_migrate(sim, cfg, mig_rates)
+        with jax.named_scope("execute"):
+            sim = phase_execute(sim, cfg)
+        with jax.named_scope("complete"):
+            sim = phase_complete(sim)
+        with jax.named_scope("cost"):
+            sim = phase_cost(sim)
 
         # paper ``update_delay_matrix`` process: periodic refresh
         # The predicate reads the scan's tick counter ``tt`` (== sim.t at
@@ -686,9 +698,10 @@ def make_tick_ext(cfg: SimConfig, policy: PolicyParams, params: RunParams,
                                                  n_hosts, n_nodes),
                                  lambda n: n, sim.net))
 
-        m = stats.collect(sim, n_arrived, sim.sched.decisions,
-                          sim.sched.migrations, params,
-                          flow_active, all_rates, soft=soft)
+        with jax.named_scope("collect"):
+            m = stats.collect(sim, n_arrived, sim.sched.decisions,
+                              sim.sched.migrations, params,
+                              flow_active, all_rates, soft=soft)
         sim = sim._replace(t=sim.t + 1.0)
         info = TickInfo(comm_rates=comm_rates, mig_rates=mig_rates,
                         flow_active=flow_active, all_rates=all_rates,
@@ -772,7 +785,9 @@ def simulate_chunk(sim: SimState, acc, t0: jnp.ndarray, cfg: SimConfig,
     def body(carry, tt):
         s, a = carry
         s, m = tick(s, tt)
-        return (s, stats.acc_update(a, m)), None
+        with jax.named_scope("collect"):
+            a = stats.acc_update(a, m)
+        return (s, a), None
 
     (sim, acc), _ = jax.lax.scan(body, (sim, acc),
                                  t0 + jnp.arange(chunk, dtype=I32))
@@ -997,9 +1012,10 @@ def simulate_telescoped(sim: SimState, acc, t0: jnp.ndarray, cfg: SimConfig,
         dt = t2 - (t + 1)
         # the skipped ticks' metrics, constant over the interval: no
         # arrivals/decisions/migrations, frozen flows, same state counts
-        m_q = stats.collect(sim, zero_i, zero_i, zero_i, params,
-                            info.flow_active, info.all_rates)
-        acc = stats.acc_update_weighted(acc, m_q, dt)
+        with jax.named_scope("collect"):
+            m_q = stats.collect(sim, zero_i, zero_i, zero_i, params,
+                                info.flow_active, info.all_rates)
+            acc = stats.acc_update_weighted(acc, m_q, dt)
         return sim, acc, t2
 
     def macro_of(seg_end):
@@ -1007,7 +1023,8 @@ def simulate_telescoped(sim: SimState, acc, t0: jnp.ndarray, cfg: SimConfig,
             sim, acc, t, n_full = carry
             sim = pin(sim)
             sim, m, info = tick_ext(sim, t)
-            acc = stats.acc_update(acc, m)
+            with jax.named_scope("collect"):
+                acc = stats.acc_update(acc, m)
             sim, acc, t2 = advance(sim, acc, t, info, jnp.asarray(False),
                                    seg_end)
             return sim, acc, t2, n_full + 1
@@ -1025,7 +1042,8 @@ def simulate_telescoped(sim: SimState, acc, t0: jnp.ndarray, cfg: SimConfig,
         sim, m, info = tick_ext(sim, seg_start)
         sim = sim._replace(net=jax.lax.cond(refresh_due, refresh_fn,
                                             lambda n: n, sim.net))
-        acc = stats.acc_update(acc, m)
+        with jax.named_scope("collect"):
+            acc = stats.acc_update(acc, m)
         sim, acc, t2 = advance(sim, acc, seg_start, info, refresh_due,
                                seg_end)
         sim, acc, _, n_full = jax.lax.while_loop(
@@ -1100,13 +1118,21 @@ def run_sim_chunked(sim0: SimState, cfg: SimConfig, policy: PolicyParams,
     sim = jax.tree.map(jnp.array, sim0)
     online = stats.online_init()
     t0 = 0
-    while t0 < horizon:
-        sz = min(chunk, horizon - t0)       # tail chunk: one extra compile
-        sim, acc = step(sim, stats.acc_init(), jnp.asarray(t0, I32),
-                        policy, params, cfg=cfg, n_hosts=n_hosts,
-                        n_nodes=n_nodes, chunk=sz)
-        online = stats.online_fold(online, acc)   # syncs; promotes to 64-bit
-        t0 += sz
+    # host spans on the profiler's clock (docs/perf.md, "Profiling a
+    # run"): one ``sim.run`` around the loop, one ``sim.chunk`` per chunk
+    # from its dispatch to the end of its fold; ``ticks`` sums to the
+    # horizon.  With the profiler off each is one cheap TraceMe check.
+    with jax.profiler.TraceAnnotation("sim.run", horizon=horizon,
+                                      chunk=chunk):
+        while t0 < horizon:
+            sz = min(chunk, horizon - t0)   # tail chunk: one extra compile
+            with jax.profiler.TraceAnnotation("sim.chunk", t0=t0, ticks=sz):
+                sim, acc = step(sim, stats.acc_init(), jnp.asarray(t0, I32),
+                                policy, params, cfg=cfg, n_hosts=n_hosts,
+                                n_nodes=n_nodes, chunk=sz)
+                # syncs; promotes to 64-bit
+                online = stats.online_fold(online, acc)
+            t0 += sz
     return sim, online
 
 
