@@ -12,15 +12,20 @@ XLA ops with HBM round-trips between them:
 This kernel runs ALL rounds and the leftover-flow tail in one
 ``pallas_call`` whose every array lives in SMEM (scalar memory), and does
 the two segment reductions the way the operation is defined: a scalar
-loop over the flattened ``[4F]`` flow slots that scatter-adds into (or
-gathers from) the ``[E]`` link arrays.  That is O(4F + E) work per round —
-the jnp path's own cost, where a vectorised one-hot formulation would pay
-O(4F * E) — and it needs no vector gather or scatter, which Mosaic has no
-general lowering for.
+loop over the flow slots that scatter-adds into (or gathers from) the
+``[E]`` link arrays.  It needs no vector gather or scatter, which Mosaic
+has no general lowering for.
+
+Only a few per cent of the ``F`` flow slots are active in a tick, so one
+pass over ``F`` lists the active flows and every later pass walks that
+list; a pass over the links walks the active flows' slots, not all ``E``
+links.  A round costs O(n_active), where the jnp path pays O(4F + E) and
+a vectorised one-hot formulation O(4F * E).
 
 Numerics: the per-link sums add the slots in flattened index order, one
-at a time — the order a serial ``segment_sum`` uses — and every per-flow
-step (fair-share divide, freeze rule ``bound <= m * 1.000001 + 1e-6``,
+at a time — the order a serial ``segment_sum`` uses; the flows the walk
+skips would add only zeros, or to the pad slot — and every per-flow step
+(fair-share divide, freeze rule ``bound <= m * 1.000001 + 1e-6``,
 local-rate min) is the jnp path's own op, so the fair allocation is meant
 to be bit-for-bit the reference's.  The Mathis min and the per-link load
 run after the kernel as the reference's own XLA ops (docs/kernels.md).
@@ -45,16 +50,17 @@ SMEM_BYTES = 1 << 20          # scalar memory of one TPU v5e core
 
 def smem_bytes(n_flows: int, n_links: int) -> int:
     """SMEM the kernel holds for ``n_flows`` flows and ``n_links`` links:
-    the [4F] slot ids, three [F] flow arrays, the [E] capacity input and
-    two [E + 1] link arrays (each padded to 1024-word tiles)."""
+    the [4F] slot ids, three [F] f32 flow arrays, the [F] i32 list of
+    active flows, the [E] capacity input and two [E + 1] link arrays (each
+    padded to 1024-word tiles)."""
     def words(n):
         return -(-n // 1024) * 1024
-    return 4 * (words(4 * n_flows) + 3 * words(n_flows) + words(n_links)
+    return 4 * (words(4 * n_flows) + 4 * words(n_flows) + words(n_links)
                 + 2 * words(n_links + 1))
 
 
 def _waterfill_kernel(lid_ref, active_ref, cap_ref, fair_ref,
-                      cap_rem, acc, bnd, *,
+                      cap_rem, acc, bnd, act, *,
                       n_rounds: int, n_flows: int, n_links: int,
                       local_rate: float, inf: float):
     """Single-invocation scalar kernel.
@@ -64,56 +70,78 @@ def _waterfill_kernel(lid_ref, active_ref, cap_ref, fair_ref,
     ``fair`` [F] f32 doubles as the per-flow alloc; ``bnd`` [F] holds the
     round's bound, or -1 once the flow is frozen.  ``cap_rem`` [E + 1] and
     ``acc`` [E + 1] are the link arrays; slot ``E`` absorbs pad slots and
-    reads as ``inf``.
+    reads as ``inf``.  ``act`` [F] i32 lists the active flows in ascending
+    order; only its first ``n_active`` entries are walked after the first
+    pass.
+
+    A pass over the links walks the active flows' slots, so a link that
+    several slots share is visited more than once, and every link step is
+    idempotent: the counts are scattered as negative numbers and turned
+    into a fair share only where still negative, and ``spend`` zeroes the
+    link's sum as it subtracts it.  A link no unfrozen flow uses keeps a
+    value nobody reads.
     """
     F, E = n_flows, n_links
 
     def each(n, body):
         jax.lax.fori_loop(0, n, lambda i, c: (body(i), c)[1], 0)
 
-    def init_flow(f):
-        act = active_ref[0, f] != 0
-        fair_ref[0, f] = jnp.where(act, F32(local_rate), F32(0.0))
+    def init_flow(f, n):
+        a = active_ref[0, f] != 0
+        fair_ref[0, f] = jnp.where(a, F32(local_rate), F32(0.0))
         # flows with no valid link freeze at the local rate up front
         any_link = ((lid_ref[0, 4 * f] < E) | (lid_ref[0, 4 * f + 1] < E)
                     | (lid_ref[0, 4 * f + 2] < E) | (lid_ref[0, 4 * f + 3] < E))
-        bnd[f] = jnp.where(act & ~any_link, F32(-1.0), F32(0.0))
+        bnd[f] = jnp.where(a & ~any_link, F32(-1.0), F32(0.0))
+        act[n] = f                    # kept only if the next flow moves n
+        return n + a.astype(I32)
 
-    def init_link(e):
-        cap_rem[e] = cap_ref[0, e]
+    n_active = jax.lax.fori_loop(0, F, init_flow, I32(0))
 
-    each(F, init_flow)
-    each(E, init_link)
+    def each_active(body):
+        """body(f) for the active flows, in ascending order."""
+        each(n_active, lambda i: body(act[i]))
+
+    def each_link(body):
+        """body(l) for every link an active flow uses, once per slot (pad
+        slot ``E`` among them)."""
+        def slots(f):
+            for s in range(4):
+                body(lid_ref[0, 4 * f + s])
+        each_active(slots)
+
+    def init_link(l):
+        # pad slot E has no capacity input; it is reset just below
+        cap_rem[l] = cap_ref[0, jnp.minimum(l, E - 1)]
+        acc[l] = F32(0.0)
+
+    each_link(init_link)
     cap_rem[E] = F32(0.0)
+    acc[E] = F32(0.0)
 
-    def unfrozen(f):
-        return (active_ref[0, f] != 0) & (bnd[f] >= 0.0)
-
-    def zero_acc():
-        def body(e):
-            acc[e] = F32(0.0)
-        each(E + 1, body)
+    def unfrozen(f):                  # f is active
+        return bnd[f] >= 0.0
 
     def scatter(weight):
-        """acc[lid[i]] += weight(i // 4) in slot order (the segment_sum)."""
+        """acc[lid[i]] += weight(i // 4) in slot order (the segment_sum);
+        inactive flows would add to pad slot E only, so they are skipped."""
         def body(f):
             w = weight(f)
             for s in range(4):
                 l = lid_ref[0, 4 * f + s]
                 acc[l] = acc[l] + w
-        each(F, body)
+        each_active(body)
+
+    def share(l):
+        cnt = -acc[l]
+        acc[l] = jnp.where(cnt > 0.0,
+                           cap_rem[l] / jnp.maximum(cnt, F32(1.0)), acc[l])
 
     def fair_share():
-        """acc: unfrozen-flow counts -> per-link fair share (inf if none)."""
-        zero_acc()
-        scatter(lambda f: jnp.where(unfrozen(f), F32(1.0), F32(0.0)))
-
-        def body(e):
-            cnt = acc[e]
-            acc[e] = jnp.where(cnt > 0.0,
-                               cap_rem[e] / jnp.maximum(cnt, F32(1.0)),
-                               F32(inf))
-        each(E, body)
+        """acc, zero on the active flows' links (init and spend leave it
+        so): unfrozen-flow counts -> per-link fair share."""
+        scatter(lambda f: jnp.where(unfrozen(f), F32(-1.0), F32(0.0)))
+        each_link(share)
         acc[E] = F32(inf)
 
     def bound_of(f):
@@ -125,13 +153,14 @@ def _waterfill_kernel(lid_ref, active_ref, cap_ref, fair_ref,
     def round_body(_, carry):
         fair_share()
 
-        def bound_pass(f, m):
+        def bound_pass(i, m):
+            f = act[i]
             live = unfrozen(f)
             b = jnp.where(live, bound_of(f), F32(inf))
             bnd[f] = jnp.where(live, b, bnd[f])
             return jnp.minimum(m, b)
 
-        m = jax.lax.fori_loop(0, F, bound_pass, F32(inf))
+        m = jax.lax.fori_loop(0, n_active, bound_pass, F32(inf))
         thr = m * F32(1.000001) + F32(1e-6)
 
         def freeze(f):
@@ -142,14 +171,18 @@ def _waterfill_kernel(lid_ref, active_ref, cap_ref, fair_ref,
             bnd[f] = jnp.where(newly, F32(-1.0), b)
             return jnp.where(newly, fair_ref[0, f], F32(0.0))
 
+        def zero(l):
+            acc[l] = F32(0.0)
+
         # the newly-frozen weight must be read before the freeze marks the
         # flow, so freeze inside the scatter's per-flow step
-        zero_acc()
+        each_link(zero)
         scatter(freeze)
 
-        def spend(e):
-            cap_rem[e] = jnp.maximum(cap_rem[e] - acc[e], F32(0.0))
-        each(E, spend)
+        def spend(l):
+            cap_rem[l] = jnp.maximum(cap_rem[l] - acc[l], F32(0.0))
+            acc[l] = F32(0.0)
+        each_link(spend)
         return carry
 
     jax.lax.fori_loop(0, n_rounds, round_body, 0)
@@ -162,7 +195,7 @@ def _waterfill_kernel(lid_ref, active_ref, cap_ref, fair_ref,
         fair_ref[0, f] = jnp.where(left,
                                 jnp.minimum(bound_of(f), F32(local_rate)),
                                 fair_ref[0, f])
-    each(F, tail)
+    each_active(tail)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rounds", "interpret",
@@ -201,7 +234,8 @@ def seg_waterfill(links: jnp.ndarray, active: jnp.ndarray,
         out_specs=smem,
         scratch_shapes=[pltpu.SMEM((E + 1,), F32),
                         pltpu.SMEM((E + 1,), F32),
-                        pltpu.SMEM((F,), F32)],
+                        pltpu.SMEM((F,), F32),
+                        pltpu.SMEM((F,), I32)],
         interpret=interpret, name="seg_waterfill",
     )(seg.reshape(1, 4 * F), active.astype(I32).reshape(1, F),
       link_bw_kbps.astype(F32).reshape(1, E))[0]

@@ -111,7 +111,10 @@ def test_use_interpret_only_on_cpu():
 
 
 # --- seg_waterfill ----------------------------------------------------------
-def random_flows(F, E, seed=0, p_active=0.8, p_local=0.1, p_lossy=0.3):
+def random_flows(F, E, seed=0, p_active=0.8, p_local=0.1, p_lossy=0.3,
+                 hot=0.0, zero_cap=0.0):
+    """Random ECMP flows; ``hot`` of the flows' first valid slot goes to
+    link 0, and ``zero_cap`` of the links have no capacity left."""
     r = np.random.default_rng(seed)
     links = r.integers(0, E, (F, 4)).astype(np.int32)
     # ECMP lists are -1 padded; local (same-host) flows have NO links
@@ -122,6 +125,10 @@ def random_flows(F, E, seed=0, p_active=0.8, p_local=0.1, p_lossy=0.3):
     bw = r.uniform(1e3, 1e5, E).astype(np.float32)
     tcp = np.where(r.uniform(size=F) < p_lossy,
                    r.uniform(10, 1e4, F), INF).astype(np.float32)
+    if hot:
+        links[(r.uniform(size=F) < hot) & (links[:, 0] >= 0), 0] = 0
+    if zero_cap:
+        bw = np.where(r.uniform(size=E) < zero_cap, 0.0, bw).astype(np.float32)
     return (jnp.asarray(links), jnp.asarray(active), jnp.asarray(bw),
             jnp.asarray(tcp))
 
@@ -144,9 +151,11 @@ def test_waterfill_matches_ref(F, E, seed):
     assert_waterfill_matches(*random_flows(F, E, seed=seed))
 
 
-def test_waterfill_no_active_flows():
-    links, _, bw, tcp = random_flows(16, 8, seed=5)
-    active = jnp.zeros(16, bool)
+@pytest.mark.parametrize("F,E,seed", [(16, 8, 5), (300, 50, 300),
+                                      (1200, 4000, 1200)])
+def test_waterfill_no_active_flows(F, E, seed):
+    links, _, bw, tcp = random_flows(F, E, seed=seed)
+    active = jnp.zeros(F, bool)
     r_k, l_k = wf_ops.seg_waterfill(links, active, bw, tcp)
     assert (np.asarray(r_k) == 0).all()
     assert (np.asarray(l_k) == 0).all()
@@ -179,10 +188,16 @@ def test_waterfill_fewer_rounds_than_bottlenecks():
     assert_waterfill_matches(*random_flows(50, 6, seed=7), n_rounds=1)
 
 
-def test_waterfill_matches_ref_under_vmap():
+@pytest.mark.parametrize("F,E,hot,lanes", [
+    (48, 10, 0.0, ((8, 0.8), (9, 0.8), (10, 0.8))),
+    # lanes with their own active counts share the kernel's scratch
+    (400, 500, 0.3, ((12, 0.01), (13, 0.1), (14, 0.6), (15, 0.0))),
+])
+def test_waterfill_matches_ref_under_vmap(F, E, hot, lanes):
     """The sweep's grid vmap batches every flow-engine input; the kernel
     must stay equal to the ref under vmap (grid-less pallas_call)."""
-    packs = [random_flows(48, 10, seed=s) for s in (8, 9, 10)]
+    packs = [random_flows(F, E, seed=s, p_active=p, hot=hot)
+             for s, p in lanes]
     links = jnp.stack([p[0] for p in packs])
     active = jnp.stack([p[1] for p in packs])
     bw = jnp.stack([p[2] for p in packs])
@@ -193,6 +208,53 @@ def test_waterfill_matches_ref_under_vmap():
     np.testing.assert_array_equal(np.asarray(r_k), np.asarray(r_ref))
     np.testing.assert_allclose(np.asarray(l_k), np.asarray(l_ref),
                                rtol=2e-6, atol=1e-3)
+
+
+@pytest.mark.parametrize("F,E,p_active,kw", [
+    (300, 400, 0.01, {}),
+    (600, 900, 0.02, {}),
+    (1200, 2000, 0.01, {}),
+    (1200, 3000, 0.02, {}),
+    (1200, 400, 0.10, {}),                        # more live slots than links
+    (800, 1500, 0.10, {}),
+    (600, 700, 0.10, dict(hot=0.8)),              # one link shared by most
+    (600, 700, 0.10, dict(zero_cap=0.3)),
+    (900, 1000, 0.05, dict(hot=0.5, zero_cap=0.2)),
+    (900, 1000, 0.05, dict(n_rounds=1)),          # the leftover tail
+    (900, 1000, 0.10, dict(hot=0.9, n_rounds=2)),
+], ids=lambda v: str(v).replace(" ", ""))
+def test_waterfill_sparse_active_matches_ref(F, E, p_active, kw):
+    """Simulator-like live shares (a few per cent of the flow slots), where
+    the kernel walks only the active flows and their slots: rates stay
+    bit-for-bit the reference's."""
+    kw = dict(kw)
+    n_rounds = kw.pop("n_rounds", 8)
+    pack = random_flows(F, E, seed=F + E, p_active=p_active, **kw)
+    assert 0 < int(pack[1].sum()) < F
+    assert_waterfill_matches(*pack, n_rounds=n_rounds)
+
+
+@pytest.mark.parametrize("n_rounds", [1, 8])
+def test_waterfill_unused_links_do_not_move_rates(n_rounds):
+    """The same flows over E links and over E padded by links no flow uses,
+    which the slot walk never visits: the rates are bit-equal and the
+    padding carries no load."""
+    links, active, bw, tcp = random_flows(300, 100, seed=11, p_active=0.1,
+                                          hot=0.3, zero_cap=0.1)
+    bw_pad = jnp.concatenate([bw, jnp.full(300, 5e4, jnp.float32)])
+    r, _ = wf_ops.seg_waterfill(links, active, bw, tcp, n_rounds=n_rounds)
+    r_pad, l_pad = wf_ops.seg_waterfill(links, active, bw_pad, tcp,
+                                        n_rounds=n_rounds)
+    np.testing.assert_array_equal(np.asarray(r_pad), np.asarray(r))
+    assert (np.asarray(l_pad)[100:] == 0).all()
+    assert_waterfill_matches(links, active, bw_pad, tcp, n_rounds=n_rounds)
+
+
+def test_waterfill_smem_fits_largest_fleet():
+    """2000 hosts / 6000 containers (F = 12000, E = 42000) fits a v5e
+    core's 1 MiB of SMEM with the active-flow list."""
+    from repro.kernels.seg_waterfill.seg_waterfill import SMEM_BYTES, smem_bytes
+    assert smem_bytes(12000, 42000) == 905_216 <= SMEM_BYTES == 1 << 20
 
 
 # --- flash attention ---------------------------------------------------------
